@@ -121,6 +121,10 @@ def run(rows: int = 100_000, k_vars: int = 20, reps: int = 3):
 if __name__ == "__main__":
     import argparse
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the JSON document here (default: stdout)")

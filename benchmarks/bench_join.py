@@ -194,6 +194,10 @@ def run(fact_rows: int = 200_000, reps: int = 3):
 if __name__ == "__main__":
     import argparse
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the JSON document here (default: stdout)")

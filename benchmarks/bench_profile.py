@@ -92,5 +92,8 @@ def run(rows: int = 100_000, reps: int = 3):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     for name, us, extra in run():
         print(f"{name},{us:.1f},{extra}")

@@ -78,5 +78,8 @@ def run(rows: int = 20_000, d: int = 16, epochs: int = 3):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     for name, us, extra in run():
         print(f"{name},{us:.1f},{extra}")

@@ -70,5 +70,8 @@ def run(B: int = 256, T: int = 64, L: int = 5, F: int = 512):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     for name, us, extra in run():
         print(f"{name},{us:.1f},{extra}")

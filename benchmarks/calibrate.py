@@ -50,6 +50,7 @@ from repro.core import Table, run_grouped, run_local, run_sharded
 from repro.core import calibration
 from repro.core.aggregates import segment_block_size
 from repro.kernels import registry as kernels
+from repro.launch import compile_cache
 from repro.launch.hlo_analysis import analyze
 from repro.methods.linregr import LinregrAggregate
 from repro.methods.sketches import CountMinAggregate
@@ -287,6 +288,7 @@ def main(argv=None) -> str:
                     help="output path (default: "
                          "benchmarks/calibration/<backend>.json)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     backend = jax.default_backend()
     if args.tiny:

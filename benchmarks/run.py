@@ -29,6 +29,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from . import bench_ivm, bench_join, bench_linregr, bench_iterative, \
         bench_plan, bench_profile, bench_serve, bench_sgd_models, \
         bench_text, roofline

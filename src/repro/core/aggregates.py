@@ -51,7 +51,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..distributed.sharding import distribute_rows, row_pspec
 from . import calibration as _calibration
-from .compat import shard_map as _compat_shard_map
 from .table import GroupedView, Table, Columns
 from .trace import record as _record
 
@@ -352,15 +351,22 @@ def _blocked_fold(agg: Aggregate, columns: Columns, mask: jax.Array | None,
                    for k, v in columns.items()}
         mask = jnp.pad(mask, (0, pad))
 
-    blocks = {k: v.reshape((nb, bs) + v.shape[1:]) for k, v in columns.items()}
-    masks = mask.reshape(nb, bs)
-
-    def step(state, xs):
-        blk, m = xs
+    def step(state, b):
+        blk, m = _block_at(columns, mask, b, bs)
         return agg.transition(state, blk, m), None
 
-    state, _ = jax.lax.scan(step, state, (blocks, masks))
+    state, _ = jax.lax.scan(step, state, jnp.arange(nb))
     return state
+
+
+def _block_at(columns: Columns, mask: jax.Array, b, bs: int):
+    """Row block ``b`` of ``columns`` and ``mask`` by dynamic slice.  An
+    ``(nb, bs, ...)`` reshape would make a TPU relayout every
+    rows-on-lanes ``(n, k)`` column (see
+    :func:`repro.core.table.take_rows`); a slice reads it in place."""
+    blk = {k: jax.lax.dynamic_slice_in_dim(v, b * bs, bs)
+           for k, v in columns.items()}
+    return blk, jax.lax.dynamic_slice_in_dim(mask, b * bs, bs)
 
 
 # Prepared-statement program cache for the local engine: the jitted pass
@@ -446,7 +452,7 @@ def run_sharded(agg: Aggregate, table: Table, *, mesh: Mesh | None = None,
         merged = agg.mesh_merge(local, row_axes)
         return agg.final(merged) if finalize else merged
 
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(in_spec, row_pspec(row_axes)),
         out_specs=P(),  # replicated result
         check_vma=False,
@@ -626,15 +632,13 @@ def segment_fold(make_agg, group_states, ops, columns: Columns,
         return jax.tree.map(_combine_leaf, ops, inits, kstates)
     n2 = next(iter(columns.values())).shape[0]
     bs = n2 // nb
-    blocks = {k: v.reshape((nb, bs) + v.shape[1:]) for k, v in columns.items()}
-    vmask = valid.reshape(nb, bs)
 
-    def step(acc, xs):
-        blk, bm, g = xs
+    def step(acc, b):
+        blk, bm = _block_at(columns, valid, b, bs)
         return segment_block_update(make_agg, group_states, ops, blk, bm,
-                                    g, acc), None
+                                    block_gids[b], acc), None
 
-    acc, _ = jax.lax.scan(step, inits, (blocks, vmask, block_gids))
+    acc, _ = jax.lax.scan(step, inits, jnp.arange(nb))
     return acc
 
 
@@ -856,7 +860,7 @@ def run_grouped(agg: Aggregate, table, group_col: str | None = None,
             merged = merge_group_states(agg, ops, states, row_axes)
             return group_final(merged)
 
-        mapped = _compat_shard_map(
+        mapped = jax.shard_map(
             shard_segment, mesh=mesh,
             in_specs=(in_spec, row_pspec(row_axes), row_pspec(row_axes)),
             out_specs=P(), check_vma=False)
@@ -923,7 +927,7 @@ def _run_grouped_masked_sharded(agg, ops, data, gids, base_mask, G,
         merged = merge_group_states(agg, ops, states, row_axes)
         return group_final(merged)
 
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         shard_masked, mesh=mesh,
         in_specs=(in_spec, row_pspec(row_axes), row_pspec(row_axes)),
         out_specs=P(),

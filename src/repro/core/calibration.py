@@ -14,9 +14,10 @@ best block, and kernel ``supports`` rankers read tuned tile parameters
 through :func:`kernel_param`.
 
 Activation is NEVER implicit — a calibration file lying on disk changes
-nothing.  ``current()`` returns a calibration only when one was
-activated programmatically (:func:`use` / :func:`activate`) or named by
-the ``MADJAX_CALIBRATION`` environment variable; with none, every
+nothing — and a calibration measured on another backend is refused.
+``current()`` returns a calibration only when one was activated
+programmatically (:func:`use` / :func:`activate`) or named by the
+``MADJAX_CALIBRATION`` environment variable; with none, every
 consumer falls back to the PR-5 heuristics unchanged (regression-tested
 in ``tests/test_plan.py``).
 
@@ -141,7 +142,20 @@ def save(cal: Calibration, path: str) -> str:
     return path
 
 
+def _check_backend(cal: Calibration) -> None:
+    """Refuse a calibration measured on another backend: its seconds,
+    blocks and tiles describe that backend, not this one.  Lazy import
+    keeps the module importable without jax."""
+    import jax
+    here = jax.default_backend()
+    if cal.backend != here:
+        raise ValueError(
+            f"calibration {cal.source or cal.timestamp!r} was measured on "
+            f"backend {cal.backend!r}, but this process runs on {here!r}")
+
+
 def activate(cal: Calibration) -> Calibration:
+    _check_backend(cal)
     _ACTIVE.append(cal)
     return cal
 
@@ -177,6 +191,7 @@ def current() -> Calibration | None:
     hit = _ENV_CACHE.get(path)
     if hit is None:
         hit = load(path)  # loud on a missing/garbled file: explicit opt-in
+        _check_backend(hit)
         _ENV_CACHE[path] = hit
     return hit
 

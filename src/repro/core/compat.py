@@ -1,11 +1,8 @@
-"""Version tolerance for the narrow slice of the JAX API that moved.
+"""Mesh construction for the one JAX the repo runs on (0.9).
 
-The codebase targets current JAX (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); older releases still carry ``shard_map`` under
-``jax.experimental`` (with ``check_rep`` instead of ``check_vma``) and
-meshes without axis types.  Every mesh / shard_map construction in the
-repo funnels through these two helpers so the rest of the code can be
-written against the current API only.
+``jax.make_mesh`` defaults every axis to ``Explicit`` sharding; the
+engines' ``shard_map`` programs are written for ``Auto`` axes, so every
+mesh in the repo is built here.
 """
 
 from __future__ import annotations
@@ -13,57 +10,18 @@ from __future__ import annotations
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` where available, the experimental one otherwise."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def random_multinomial(key, n: int, p):
-    """``jax.random.multinomial`` where available; categorical+histogram
-    fallback (same distribution, different draws) otherwise."""
-    if hasattr(jax.random, "multinomial"):
-        return jax.random.multinomial(key, n, p)
-    import jax.numpy as jnp
-    idx = jax.random.categorical(key, jnp.log(p), shape=(int(n),))
-    return jnp.zeros(p.shape[-1], p.dtype).at[idx].add(1)
-
-
-def axis_size(axis_name) -> "jax.Array | int":
-    """``jax.lax.axis_size`` where available; psum-of-ones fallback."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict: older JAX returns a
-    one-element list of per-device dicts."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost or {})
-
-
 def make_mesh(axis_shapes: tuple[int, ...], axis_names: tuple[str, ...],
               devices=None):
-    """An Auto-typed mesh on new JAX; a plain mesh where types don't exist.
+    """An Auto-typed mesh over every device, or over ``devices``.
 
     ``devices`` selects an explicit device subset (e.g. meshes of 1/2/4
     devices on an 8-device host for device-count scaling benchmarks) —
     ``jax.make_mesh`` requires the product of ``axis_shapes`` to cover
-    every addressable device, so subsets build a plain ``Mesh`` directly
-    on every JAX version."""
+    every addressable device, so subsets build a plain ``Mesh``."""
     if devices is not None:
         import numpy as np
         return jax.sharding.Mesh(
             np.asarray(devices).reshape(axis_shapes), axis_names)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -60,11 +60,10 @@ from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import row_pspec
 from .aggregates import (
-    Aggregate, _blocked_fold, _collective_leaf, probe_segment_ops,
+    Aggregate, _block_at, _blocked_fold, _collective_leaf, probe_segment_ops,
     run_local, run_sharded, run_stream, segment_block_size,
     segment_block_update,
 )
-from .compat import shard_map as _compat_shard_map
 from .table import Table, Columns
 from .trace import record as _record
 
@@ -339,7 +338,7 @@ def fit(task: IterativeTask, table: Table, *, max_iters: int = 100,
             state, aux, n, m, trace = out
             return task.mesh_epilogue(state, row_axes), aux, n, m, trace
 
-        mapped = _compat_shard_map(
+        mapped = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(in_spec, row_pspec(row_axes), P()),
             out_specs=P(), check_vma=False)
@@ -624,10 +623,7 @@ def _fit_grouped_segment(task, table, key_col, G, states0, max_iters, tol,
             def blk_body(carry):
                 b, acc = carry
                 j = blk_idx[b]
-                blk = jax.tree.map(
-                    lambda c: jax.lax.dynamic_slice_in_dim(c, j * bs, bs),
-                    cols)
-                bm = jax.lax.dynamic_slice_in_dim(valid, j * bs, bs)
+                blk, bm = _block_at(cols, valid, j, bs)
                 g = bgids[j]
                 acc = segment_block_update(task.make_aggregate, states,
                                            ops, blk, bm, g, acc)
@@ -700,7 +696,7 @@ def _fit_grouped_segment(task, table, key_col, G, states0, max_iters, tol,
     if mesh is not None:
         col_spec = jax.tree.map(
             lambda v: row_pspec(row_axes, v.ndim), cols)
-        go = _compat_shard_map(
+        go = jax.shard_map(
             go, mesh=mesh,
             in_specs=(col_spec, row_pspec(row_axes), row_pspec(row_axes),
                       P(), P()),

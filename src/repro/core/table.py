@@ -33,6 +33,23 @@ def _n_rows(columns: Columns) -> int:
     return next(iter(sizes.values()))
 
 
+@jax.jit
+def take_rows(v: jax.Array, idx: jax.Array) -> jax.Array:
+    """``v[idx]`` along the row axis, one feature column at a time.
+
+    A TPU lays an ``(n, k)`` f32 column out rows-on-lanes when ``k`` is
+    not a multiple of 128, and a whole-row gather then relayouts it to
+    rows-on-sublanes and back: about ``2 * n * 128 * 4`` bytes of scratch
+    (10 GB at 10M rows), whatever ``k``.  ``k`` one-dimensional gathers
+    read and write the rows-on-lanes layout as it is.
+    """
+    if v.ndim == 1:
+        return v[idx]
+    flat = v.reshape(v.shape[0], -1)
+    out = jax.lax.map(lambda col: col[idx], flat.T)
+    return out.T.reshape(idx.shape + v.shape[1:])
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Table:
@@ -132,7 +149,7 @@ class Table:
         otherwise the result lies about its layout to the sharded
         engines (new arrays would stay ``SingleDeviceSharding``).
         """
-        if self.mesh is None:
+        if self.mesh is None or not columns:
             return columns
         from ..distributed.sharding import distribute_rows
         segs = int(np.prod([self.mesh.shape[a] for a in self.row_axes]))
@@ -378,7 +395,11 @@ class Table:
         offsets = jnp.searchsorted(
             sorted_gids, jnp.arange(num_groups + 1, dtype=jnp.int32)
         ).astype(jnp.int32)
-        data = {k: v[perm] for k, v in self.columns.items() if k != key_col}
+        # the partitioned copy keeps the table's row sharding: the gather
+        # leaves it replicated, a whole table on every chip
+        data = self._place_rows({k: take_rows(v, perm)
+                                 for k, v in self.columns.items()
+                                 if k != key_col})
         return GroupedView(
             Table(data, self.mesh, self.row_axes), sorted_gids, perm,
             num_groups, jnp.diff(offsets), offsets,
@@ -477,7 +498,7 @@ class GroupedView:
                 valid_np = np.concatenate(
                     [valid_np, np.zeros(extra * bs, bool)])
         src = jnp.asarray(src_np)
-        cols = {k: v[src] for k, v in self.table.columns.items()}
+        cols = {k: take_rows(v, src) for k, v in self.table.columns.items()}
         valid = jnp.asarray(valid_np)
         if base_mask is not None:
             valid = valid & jnp.asarray(base_mask)[src]

@@ -20,7 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..core.compat import axis_size
 
 
 def quantize_int8(x: jax.Array, key: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -68,7 +67,7 @@ def compressed_psum(grads, error, key, axis: str):
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     e_leaves = treedef.flatten_up_to(error)
     keys = jax.random.split(key, len(leaves))
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     outs, new_es = [], []
     for g, e, k in zip(leaves, e_leaves, keys):
         corrected = g.astype(jnp.float32) + e

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.compat import shard_map as _compat_shard_map
 
 
 def splitk_partial(q, k_shard, v_shard, valid_shard):
@@ -64,7 +63,7 @@ def make_splitk_decode_attention(mesh: Mesh, *, seq_axis: str = "model",
         out = splitk_combine(m, l, acc, seq_axis)
         return out.reshape(b, 1, h, dh).astype(q.dtype)
 
-    return _compat_shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(batch_axes, None, None, None),
                   P(batch_axes, seq_axis, None, None),

@@ -34,7 +34,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.layers import ParamStore
 
-from ..core.compat import shard_map as _compat_shard_map
 
 
 def init_moe_a2a(store: ParamStore, cfg, name="moe"):
@@ -152,7 +151,7 @@ def make_run_moe_a2a(mesh: Mesh, cfg, *, batch_axes=("pod", "data"),
         P(expert_axis, None, fsdp_axis),        # w_down
         P(batch_axes, expert_axis, None),       # x: batch x seq-shard x d
     )
-    mapped = _compat_shard_map(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
         out_specs=(P(batch_axes, expert_axis, None), P(), P()),
         check_vma=False)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.compat import shard_map as _compat_shard_map
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
@@ -81,7 +80,7 @@ def make_pipeline(mesh: Mesh, stage_fn, *, stage_axis: str = "pod",
 
     def pipe(params_stacked, x):
         pspec = jax.tree.map(lambda _: P(stage_axis), params_stacked)
-        return _compat_shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(pspec, P()),
             out_specs=P(),

@@ -79,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     static_argnames=("causal", "scale", "tile_q", "tile_k", "interpret"))
 def flash_attention_padded(q, k, v, *, scale: float, causal: bool = True,
                            tile_q: int = 256, tile_k: int = 256,
-                           interpret: bool = True):
+                           interpret: bool):
     """q (BHq, S, D), k/v (BHk, S, D); S % tile == 0, BHq % BHk == 0.
 
     Returns (BHq, S, D) in q.dtype.

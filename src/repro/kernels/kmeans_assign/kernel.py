@@ -52,8 +52,7 @@ def _kernel(x_ref, c_ref, m_ref, assign_ref, mind_ref, sums_ref, counts_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def assign_reduce_padded(x, c, m, *, tile_n: int = 512,
-                         interpret: bool = True):
+def assign_reduce_padded(x, c, m, *, tile_n: int, interpret: bool):
     """x (N, D), c (K, D), m (N, 1); N % tile_n == 0.
 
     Returns assign (N,1) i32, mind (N,1) f32, sums (K,D) f32, counts (K,1)

@@ -217,9 +217,16 @@ def _ensure_builtins() -> None:
 
     from .countmin import ops as cm_ops, ref as cm_ref
 
-    def countmin_supports(items, mask, depth, width, *, tile_n=2048):
-        t = _calibrated("countmin", "tile_n")
-        return {"tile_n": int(t)} if t else True
+    def countmin_supports(items, mask, depth, width, *, tile_n=None):
+        # the tile shrinks with the width so the kernel's (width, tile)
+        # one-hot stays within VMEM; a caller's explicit tile is final
+        cap = cm_ops.max_tile(width)
+        if tile_n is not None:
+            return tile_n <= cap
+        if not cap:
+            return False
+        t = _calibrated("countmin", "tile_n") or 2048
+        return {"tile_n": min(int(t), cap)}
 
     register("countmin", ref=cm_ref.countmin_block_ref,
              pallas=cm_ops.countmin_block, supports=countmin_supports,

@@ -23,13 +23,21 @@ import jax.numpy as jnp
 
 from ...methods.sketches import _PRIMES, _fmix32, _hash_rows, _lowest_set_bit
 
+_HIGHEST = jax.lax.Precision.HIGHEST   # as the linregr transition
 
-def _blocked(arr, nb):
-    n2 = arr.shape[0]
+
+def _block_size(n2: int, nb: int) -> int:
     if nb <= 0 or n2 % nb:
         raise ValueError(f"segment_fold ref: {n2} rows do not form {nb} "
                          "equal blocks")
-    return arr.reshape((nb, n2 // nb) + arr.shape[1:])
+    return n2 // nb
+
+
+def _at(arr, b, bs: int):
+    """Block ``b`` by dynamic slice, as the generic path reads it (an
+    ``(nb, bs, ...)`` reshape would relayout a TPU's rows-on-lanes
+    ``(n, k)`` columns)."""
+    return jax.lax.dynamic_slice_in_dim(arr, b * bs, bs)
 
 
 def segment_linregr_ref(x, y, valid, bgids, *, num_groups: int):
@@ -38,7 +46,7 @@ def segment_linregr_ref(x, y, valid, bgids, *, num_groups: int):
     nb = bgids.shape[0]
     k = x.shape[1]
     f = x.dtype
-    xb, yb, vb = _blocked(x, nb), _blocked(y, nb), _blocked(valid, nb)
+    bs = _block_size(x.shape[0], nb)
     acc = {
         "xtx": jnp.zeros((num_groups, k, k), f),
         "xty": jnp.zeros((num_groups, k), f),
@@ -47,14 +55,15 @@ def segment_linregr_ref(x, y, valid, bgids, *, num_groups: int):
         "n": jnp.zeros((num_groups,), jnp.float32),
     }
 
-    def step(acc, xs):
-        xq, yq, m, g = xs
+    def step(acc, b):
+        xq, yq, m = _at(x, b, bs), _at(y, b, bs), _at(valid, b, bs)
+        g = bgids[b]
         # the aggregate's transition, verbatim (mask-multiply forms)
         xm = xq * m[:, None].astype(xq.dtype)
         ym = yq * m.astype(yq.dtype)
         bstate = {
-            "xtx": xm.T @ xm,
-            "xty": xm.T @ ym,
+            "xtx": jnp.matmul(xm.T, xm, precision=_HIGHEST),
+            "xty": jnp.matmul(xm.T, ym, precision=_HIGHEST),
             "y_sum": jnp.sum(ym),
             "y_sq": jnp.sum(ym * ym),
             "n": jnp.sum(m.astype(jnp.float32)),
@@ -62,7 +71,7 @@ def segment_linregr_ref(x, y, valid, bgids, *, num_groups: int):
         return jax.tree.map(lambda a, b: a.at[g[None]].add(b[None]),
                             acc, bstate), None
 
-    acc, _ = jax.lax.scan(step, acc, (xb, yb, vb, bgids))
+    acc, _ = jax.lax.scan(step, acc, jnp.arange(nb))
     return acc
 
 
@@ -70,19 +79,19 @@ def segment_countmin_ref(items, valid, bgids, *, depth: int, width: int,
                          num_groups: int):
     """Whole-fold Count-Min stack: (N2,) items -> (G, depth, width) i32."""
     nb = bgids.shape[0]
-    ib = _blocked(items.astype(jnp.int32), nb)
-    vb = _blocked(valid, nb)
+    bs = _block_size(items.shape[0], nb)
+    items = items.astype(jnp.int32)
     acc = jnp.zeros((num_groups, depth, width), jnp.int32)
 
-    def step(acc, xs):
-        it, m, g = xs
+    def step(acc, b):
+        it, m, g = _at(items, b, bs), _at(valid, b, bs), bgids[b]
         idx = _hash_rows(it, depth, width)                   # (depth, bs)
         upd = m.astype(jnp.int32)
         bstate = jax.vmap(lambda s, i: s.at[i].add(upd))(
             jnp.zeros((depth, width), jnp.int32), idx)
         return acc.at[g[None]].add(bstate[None]), None
 
-    acc, _ = jax.lax.scan(step, acc, (ib, vb, bgids))
+    acc, _ = jax.lax.scan(step, acc, jnp.arange(nb))
     return acc
 
 
@@ -91,13 +100,13 @@ def segment_fm_ref(items, valid, bgids, *, num_hashes: int, bits: int,
     """Whole-fold Flajolet-Martin stack: (N2,) items -> (G, H, bits) i32
     {0,1} bitmaps, max-merged per block."""
     nb = bgids.shape[0]
-    ib = _blocked(items.astype(jnp.uint32), nb)
-    vb = _blocked(valid, nb)
+    bs = _block_size(items.shape[0], nb)
+    items = items.astype(jnp.uint32)
     acc = jnp.zeros((num_groups, num_hashes, bits), jnp.int32)
     mults = _PRIMES[:num_hashes][:, None]
 
-    def step(acc, xs):
-        it, m, g = xs
+    def step(acc, b):
+        it, m, g = _at(items, b, bs), _at(valid, b, bs), bgids[b]
         h = _fmix32(it[None, :] * mults + mults)             # (H, bs)
         r = _lowest_set_bit(h, bits)
         onehots = jax.nn.one_hot(r, bits, dtype=jnp.int32)
@@ -105,5 +114,5 @@ def segment_fm_ref(items, valid, bgids, *, num_hashes: int, bits: int,
         bstate = jnp.max(onehots, axis=1)                    # (H, bits)
         return acc.at[g[None]].max(bstate[None]), None
 
-    acc, _ = jax.lax.scan(step, acc, (ib, vb, bgids))
+    acc, _ = jax.lax.scan(step, acc, jnp.arange(nb))
     return acc

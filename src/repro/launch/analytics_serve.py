@@ -27,6 +27,7 @@ import time
 import numpy as np
 
 from ..core import AnalyticsServer, Session, Table, trace_execution
+from . import compile_cache
 
 
 def _make_table(rows: int, dims: int, seed: int = 0) -> Table:
@@ -82,18 +83,29 @@ def serve_analytics(*, rows: int = 100_000, dims: int = 8,
                 "item": rng.integers(0, 1000, m).astype(np.int32)})
             print(f"round {rnd}: ingest +{m} rows -> cache evicted "
                   f"(total {server.stats['evicted']})")
-        results: list = [None] * sessions
+        errors: list = [None] * sessions
+
+        def analyst(i: int) -> None:
+            try:
+                _analyst_round(pool[i], table, passive)
+            except BaseException as e:  # re-raised on the main thread below
+                errors[i] = e
+
         with trace_execution() as t:
             t0 = time.perf_counter()
-            threads = [threading.Thread(
-                target=lambda i=i: results.__setitem__(
-                    i, _analyst_round(pool[i], table, passive)))
-                for i in range(sessions)]
+            threads = [threading.Thread(target=analyst, args=(i,))
+                       for i in range(sessions)]
             for th in threads:
                 th.start()
             for th in threads:
                 th.join()
             dt = time.perf_counter() - t0
+        failed = [e for e in errors if e is not None]
+        if failed:
+            server.close()
+            raise RuntimeError(
+                f"round {rnd}: {len(failed)} of {sessions} analyst sessions "
+                "failed") from failed[0]
         summ = t.summary()
         stmts = sessions * 4
         print(f"round {rnd}: {sessions} sessions x 4 statements | "
@@ -127,6 +139,7 @@ def main():
                     help="ingest a delta every K rounds (0 = never)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     serve_analytics(rows=args.rows, dims=args.dims,
                     sessions=args.sessions, rounds=args.rounds,
                     window_size=args.window_size, drain=args.drain,

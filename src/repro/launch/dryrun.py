@@ -271,8 +271,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    from ..core.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     if _DUMP_HLO:
         with open(_DUMP_HLO, "w") as f:

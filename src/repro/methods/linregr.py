@@ -11,6 +11,7 @@ computes the condition number of ``X^T X``).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,11 @@ from ..core.plan import GroupedScanAgg, JoinedGroupedScanAgg, ScanAgg, \
     execute
 from ..core.table import Table
 from ..kernels.registry import dispatch, resolve_impl
+
+# Sufficient statistics and the solve run at full f32 precision: a TPU's
+# DEFAULT matmul precision rounds f32 operands to bf16, which moves the
+# coefficients by about 1e-3 relative.
+_mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclasses.dataclass
@@ -77,8 +83,8 @@ class LinregrAggregate(Aggregate):
         else:
             # The paper's v0.3 lesson: express the rank-1 updates as one
             # rank-B update (k,B)@(B,k) — systolic-array native.
-            xtx = x.T @ x
-            xty = x.T @ y
+            xtx = _mm(x.T, x)
+            xty = _mm(x.T, y)
         return {
             "xtx": state["xtx"] + xtx,
             "xty": state["xty"] + xty,
@@ -95,11 +101,14 @@ class LinregrAggregate(Aggregate):
         w, v = jnp.linalg.eigh(xtx)
         eps = jnp.finfo(xtx.dtype).eps * d * jnp.max(jnp.abs(w))
         inv_w = jnp.where(w > eps, 1.0 / w, 0.0)
-        pinv = (v * inv_w) @ v.T
-        coef = pinv @ xty
+        pinv = _mm(v * inv_w, v.T)
+        coef = _mm(pinv, xty)
         cond = jnp.max(jnp.abs(w)) / jnp.maximum(jnp.min(jnp.abs(w)), 1e-30)
 
-        sse = s["y_sq"] - 2.0 * coef @ xty + coef @ (xtx @ coef)
+        # coef·XᵀX·coef == coef·Xᵀy for the pseudo-inverse solution, so
+        # SSE = yᵀy - coef·Xᵀy: one f32 cancellation instead of two, which
+        # keeps sse (and r2, std_err) stable to rounding of the terms
+        sse = s["y_sq"] - _mm(coef, xty)
         tss = s["y_sq"] - (s["y_sum"] ** 2) / n
         r2 = 1.0 - sse / jnp.maximum(tss, 1e-30)
         dof = jnp.maximum(n - d, 1.0)

@@ -16,6 +16,7 @@ Table-2 benchmark.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,10 @@ from ..core.convex import ConvexProgram, sgd as sgd_solver, parallel_sgd
 from ..core.iterative import IterativeTask
 from ..core.plan import IterativeFit, execute
 from ..core.table import Table
+
+# Full f32 precision for the IRLS statistics (a TPU's DEFAULT matmul
+# precision rounds f32 operands to bf16).
+_mm = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclasses.dataclass
@@ -59,15 +64,15 @@ class IRLSAggregate(Aggregate):
         x = block["x"]
         y = block["y"]
         m = mask.astype(x.dtype)
-        eta = x @ self.beta
+        eta = _mm(x, self.beta)
         p = jax.nn.sigmoid(eta)
         w = jnp.maximum(p * (1.0 - p), 1e-10) * m          # D diagonal
         z = eta + (y - p) / jnp.maximum(p * (1.0 - p), 1e-10)
         xw = x * w[:, None]
         ll = jnp.sum(m * (y * eta - jax.nn.softplus(eta)))
         return {
-            "xdx": state["xdx"] + xw.T @ x,
-            "xdz": state["xdz"] + xw.T @ z,
+            "xdx": state["xdx"] + _mm(xw.T, x),
+            "xdz": state["xdz"] + _mm(xw.T, z),
             "ll": state["ll"] + ll,
             "n": state["n"] + jnp.sum(m),
         }

@@ -85,8 +85,7 @@ def test_real_lowering_matches_hand_count(key):
     # fwd dot + 2 bwd dots per layer, 6 layers
     expected = 3 * 2 * 16 * 64 * 64 * 6
     assert res["dot_flops"] == pytest.approx(expected, rel=0.35)
-    from repro.core.compat import cost_analysis
-    assert res["dot_flops"] > cost_analysis(compiled).get("flops", 0.0)
+    assert res["dot_flops"] > compiled.cost_analysis().get("flops", 0.0)
     assert res["unknown_whiles"] == []
 
 

@@ -29,6 +29,7 @@ from repro.core import (
 from repro.methods.linregr import LinregrAggregate
 from repro.methods.sketches import CountMinAggregate, FMAggregate
 from repro.core.templates import ProfileAggregate
+from repro.distributed.sharding import row_pspec
 
 from strategies import Draw, cases, group_layout
 
@@ -173,7 +174,7 @@ class TestEmptyViewBlocks:
 class TestShardingInvariants:
     def _assert_row_sharded(self, arr):
         assert isinstance(arr.sharding, NamedSharding)
-        assert arr.sharding.spec[0] == ("data",)
+        assert arr.sharding.spec == row_pspec(("data",), arr.ndim)
 
     def test_with_column_distributes_new_column(self, mesh1):
         t = Table.from_columns({"a": np.arange(8.0)}).distribute(mesh1)

@@ -178,8 +178,7 @@ def test_lda_perplexity_decreases(keys):
     for d in range(150):
         kd = jax.random.fold_in(keys[4], d)
         th = jax.random.dirichlet(kd, jnp.full((K,), 0.3))
-        from repro.core.compat import random_multinomial
-        docs.append(random_multinomial(jax.random.fold_in(kd, 1), 80,
+        docs.append(jax.random.multinomial(jax.random.fold_in(kd, 1), 80,
                                        th @ topics))
     tbl = Table.from_columns({"counts": jnp.stack(docs)})
     learned, trace = lda_fit(tbl, K, V, max_iters=10, key=keys[5])

@@ -55,7 +55,11 @@ def test_sharded_uda_8dev():
                                    np.asarray(sm.coef),
                                    rtol=1e-4, atol=1e-5)
         assert float(sm.num_rows) == float(mask.sum())
-        y = (tbl["y"] > 0).astype(jnp.float32)
+        # Bernoulli labels on the logit (not the sign of a low-noise
+        # linear signal, which is near-separable and sends the IRLS
+        # coefficients off toward infinity)
+        u = jax.random.uniform(jax.random.PRNGKey(1), (tbl.n_rows,))
+        y = (u < jax.nn.sigmoid(tbl["y"])).astype(jnp.float32)
         ctbl = tbl.with_column("y", y)
         fl = fit(IRLSTask(), ctbl, max_iters=20, mask=mask)
         fs = fit(IRLSTask(), ctbl.distribute(mesh), max_iters=20,
@@ -270,8 +274,7 @@ def test_compressed_psum_8dev():
             out, new_e = compressed_psum(grads, err, key, "pod")
             return out["w"]
 
-        from repro.core.compat import shard_map
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"), P()), out_specs=P("pod"),
             check_vma=False))
         keys = jax.random.PRNGKey(1)
@@ -312,3 +315,37 @@ def test_sharded_train_step_8dev():
         print("TRAIN-OK", [round(l, 3) for l in losses])
     """)
     assert "TRAIN-OK" in out
+
+
+def test_group_by_view_of_a_distributed_table_stays_distributed_4dev():
+    """Each chip holds a quarter of the partitioned copy, not all of it."""
+    run_py("""
+        import jax, numpy as np
+        from repro.core import Table
+        from repro.core.compat import make_mesh
+        from repro.distributed.sharding import row_pspec
+        mesh = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        t = Table.from_columns({"x": np.arange(64.0).reshape(32, 2),
+                                "y": np.arange(32.0),
+                                "g": np.arange(32) % 3}).distribute(mesh)
+        view = t.group_by("g")
+        for name, col in view.table.columns.items():
+            assert col.sharding.spec == row_pspec(("data",), col.ndim), name
+            assert [s.data.shape[0] for s in col.addressable_shards] \
+                == [8] * 4, name
+    """)
+
+
+def test_chip_smoke_four_chip_phases_4dev():
+    """``chip_smoke.py --four-chips`` phases at a tiny size on 4 of the
+    forced host devices: sharded grouped scan, fit and joined scan, each
+    against the same statements on one device."""
+    run_py("""
+        import jax
+        import chip_smoke as cs
+        size = cs.Size(rows=4096, k=8, groups=8, stores=32, items=1000,
+                       width=256, append_rows=64, sessions=3, chunk=1000)
+        checks = cs.Checks()
+        cs.run_four_chips(size, 0, checks, jax.devices()[:4])
+        assert checks.failed == [], checks.failed
+    """)

@@ -383,6 +383,22 @@ def test_calibration_flips_grouped_choice_and_explain(table):
     assert "cost=1024" in txt2
 
 
+def test_calibration_refused_on_another_backend(monkeypatch, tmp_path):
+    """A calibration measured on one backend never drives another's
+    plans: activation by use() and by MADJAX_CALIBRATION both raise."""
+    from repro.core import calibration
+    cal = _cal({})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="measured on backend 'cpu'"):
+        with calibration.use(cal):
+            pass
+    assert calibration.current() is None
+    path = calibration.save(cal, str(tmp_path / "cpu.json"))
+    monkeypatch.setenv("MADJAX_CALIBRATION", path)
+    with pytest.raises(ValueError, match="measured on backend 'cpu'"):
+        calibration.current()
+
+
 def test_calibration_partial_coverage_falls_back(table):
     """Measured seconds never rank against heuristic row counts: a
     calibration missing ANY candidate leaves the whole selection on the

@@ -146,16 +146,21 @@ def test_forced_pallas_on_tpu_unsupported_shape_raises(monkeypatch):
 
 
 def test_supports_runs_on_shape_structs():
-    """Host-side resolution probes supports with ShapeDtypeStructs."""
-    x = jax.ShapeDtypeStruct((96, 3), jnp.float32)
-    y = jax.ShapeDtypeStruct((96,), jnp.float32)
-    valid = jax.ShapeDtypeStruct((96,), jnp.bool_)
+    """Host-side resolution probes supports with ShapeDtypeStructs; the
+    compiled kernels stream whole 128-lane blocks only."""
+    x = jax.ShapeDtypeStruct((768, 3), jnp.float32)
+    y = jax.ShapeDtypeStruct((768,), jnp.float32)
+    valid = jax.ShapeDtypeStruct((768,), jnp.bool_)
     gids = jax.ShapeDtypeStruct((6,), jnp.int32)
     assert sf_ops.segment_linregr_supports(x, y, valid, gids,
                                            num_groups=G) is True
-    bad = jax.ShapeDtypeStruct((96, 3), jnp.float64)
+    bad = jax.ShapeDtypeStruct((768, 3), jnp.float64)
     assert sf_ops.segment_linregr_supports(bad, y, valid, gids,
                                            num_groups=G) is False
+    ragged = jax.ShapeDtypeStruct((96,), jnp.float32)   # 16-row blocks
+    assert sf_ops.segment_linregr_supports(
+        jax.ShapeDtypeStruct((96, 3), jnp.float32), ragged, ragged, gids,
+        num_groups=G) is False
 
 
 def test_supports_ranker_tuned_kwargs(monkeypatch):
@@ -189,9 +194,9 @@ def test_calibration_feeds_kernel_rankers(monkeypatch):
     assert entry.supports(x, y) is True
     cal = Calibration(backend="tpu", timestamp="t", engines={},
                       kernels={"xtx": {"tile_n": 256}}, grouped_block=[])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with use(cal):
         assert entry.supports(x, y) == {"tile_n": 256}
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert entry.resolve("auto", x, y) == ("pallas", {"tile_n": 256})
 
 

@@ -959,3 +959,18 @@ class TestMaterializeThreadSafety:
 class _NeverFlush:
     def flush(self, timeout=None):
         return 0
+
+
+def test_analytics_serve_demo_reraises_session_errors(monkeypatch):
+    """The serving demo fails loudly when an analyst thread raises: the
+    error reaches the caller (and the CLI's exit code), not just stderr."""
+    from repro.launch import analytics_serve
+
+    def boom(*args, **kwargs):
+        raise ZeroDivisionError("analyst")
+
+    monkeypatch.setattr(analytics_serve, "_analyst_round", boom)
+    with pytest.raises(RuntimeError, match="analyst sessions failed") as ei:
+        analytics_serve.serve_analytics(rows=64, dims=2, sessions=2,
+                                        rounds=1)
+    assert isinstance(ei.value.__cause__, ZeroDivisionError)
