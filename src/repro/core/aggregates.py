@@ -52,7 +52,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..distributed.sharding import distribute_rows, row_pspec
 from . import calibration as _calibration
 from .table import GroupedView, Table, Columns
-from .trace import record as _record
+from .trace import record as _record, span as _span
 
 S = TypeVar("S")  # transition state pytree
 R = TypeVar("R")  # result pytree
@@ -335,28 +335,29 @@ def _collective_leaf(op: str, x, *, axes):
 def _blocked_fold(agg: Aggregate, columns: Columns, mask: jax.Array | None,
                   block_size: int | None) -> Any:
     """Fold ``transition`` over row blocks of ``columns`` on one shard."""
-    n = next(iter(columns.values())).shape[0]
-    if mask is None:
-        mask = jnp.ones((n,), jnp.bool_)
-    state = agg.init(columns)
-    if block_size is None or block_size >= n:
-        return agg.transition(state, columns, mask)
+    with jax.named_scope("madjax.fold"):
+        n = next(iter(columns.values())).shape[0]
+        if mask is None:
+            mask = jnp.ones((n,), jnp.bool_)
+        state = agg.init(columns)
+        if block_size is None or block_size >= n:
+            return agg.transition(state, columns, mask)
 
-    bs = block_size
-    nb = -(-n // bs)  # ceil
-    padded = nb * bs
-    if padded != n:
-        pad = padded - n
-        columns = {k: jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1))
-                   for k, v in columns.items()}
-        mask = jnp.pad(mask, (0, pad))
+        bs = block_size
+        nb = -(-n // bs)  # ceil
+        padded = nb * bs
+        if padded != n:
+            pad = padded - n
+            columns = {k: jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1))
+                       for k, v in columns.items()}
+            mask = jnp.pad(mask, (0, pad))
 
-    def step(state, b):
-        blk, m = _block_at(columns, mask, b, bs)
-        return agg.transition(state, blk, m), None
+        def step(state, b):
+            blk, m = _block_at(columns, mask, b, bs)
+            return agg.transition(state, blk, m), None
 
-    state, _ = jax.lax.scan(step, state, jnp.arange(nb))
-    return state
+        state, _ = jax.lax.scan(step, state, jnp.arange(nb))
+        return state
 
 
 def _block_at(columns: Columns, mask: jax.Array, b, bs: int):
@@ -381,20 +382,36 @@ _LOCAL_JIT_MAX = 256
 
 
 def _local_jit(agg: Aggregate, block_size, finalize: bool = True):
+    """``(program, "hit" | "miss")``: the prepared program and whether
+    it was found in the cache."""
     key = (id(agg), block_size, finalize)
     hit = _LOCAL_JIT_CACHE.get(key)
     if hit is not None:
-        return hit[1]
+        return hit[1], "hit"
 
     def go(columns, mask):
         state = _blocked_fold(agg, columns, mask, block_size)
-        return agg.final(state) if finalize else state
+        return _finalize(agg.final, state) if finalize else state
 
     fn = jax.jit(go)
     if len(_LOCAL_JIT_CACHE) >= _LOCAL_JIT_MAX:
         _LOCAL_JIT_CACHE.pop(next(iter(_LOCAL_JIT_CACHE)))
     _LOCAL_JIT_CACHE[key] = (agg, fn)
-    return fn
+    return fn, "miss"
+
+
+def _finalize(final, state):
+    """``final(state)`` under the ``madjax.finalize`` scope."""
+    with jax.named_scope("madjax.finalize"):
+        return final(state)
+
+
+def _group_finalizer(agg: Aggregate, finalize: bool) -> Callable:
+    """``vmap(agg.final)`` under the ``madjax.finalize`` scope, or the
+    identity for raw states."""
+    if not finalize:
+        return lambda s: s
+    return partial(_finalize, jax.vmap(agg.final))
 
 
 def run_local(agg: Aggregate, table: Table, *, block_size: int | None = None,
@@ -410,10 +427,14 @@ def run_local(agg: Aggregate, table: Table, *, block_size: int | None = None,
     ("scan" normally; the materialize layer passes "delta" when this
     pass folds only appended rows)."""
     _record(trace_kind, engine="local", rows=table.n_rows)
-    if not jit:
-        state = _blocked_fold(agg, dict(table.columns), mask, block_size)
-        return agg.final(state) if finalize else state
-    return _local_jit(agg, block_size, finalize)(dict(table.columns), mask)
+    with _span("fold.dispatch", engine="local") as sp:
+        if not jit:
+            sp.detail["prepared"] = "miss"
+            state = _blocked_fold(agg, dict(table.columns), mask,
+                                  block_size)
+            return agg.final(state) if finalize else state
+        fn, sp.detail["prepared"] = _local_jit(agg, block_size, finalize)
+        return fn(dict(table.columns), mask)
 
 
 # ---------------------------------------------------------------------------
@@ -449,8 +470,9 @@ def run_sharded(agg: Aggregate, table: Table, *, mesh: Mesh | None = None,
 
     def shard_fn(columns, mask):
         local = _blocked_fold(agg, columns, mask, block_size)
-        merged = agg.mesh_merge(local, row_axes)
-        return agg.final(merged) if finalize else merged
+        with jax.named_scope("madjax.merge"):
+            merged = agg.mesh_merge(local, row_axes)
+        return _finalize(agg.final, merged) if finalize else merged
 
     mapped = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(in_spec, row_pspec(row_axes)),
@@ -617,29 +639,30 @@ def segment_fold(make_agg, group_states, ops, columns: Columns,
     leaf combinators — bit-identical to the generic scan for exact-state
     aggregates because init is the merge identity.
     """
-    lead = jax.tree.leaves(group_states)[0].shape[0]
-    if lead != num_groups:
-        raise ValueError(f"segment_fold: group_states lead axis {lead} "
-                         f"!= num_groups={num_groups}")
-    inits = jax.vmap(lambda s: make_agg(s).init(columns))(group_states)
-    nb = block_gids.shape[0]
-    if nb == 0:
-        return inits
-    if kernel_impl is not None and agg is not None \
-            and getattr(agg, "segment_kernel", None):
-        kstates = agg.segment_kernel_fold(columns, valid, block_gids,
-                                          num_groups, kernel_impl)
-        return jax.tree.map(_combine_leaf, ops, inits, kstates)
-    n2 = next(iter(columns.values())).shape[0]
-    bs = n2 // nb
+    with jax.named_scope("madjax.fold"):
+        lead = jax.tree.leaves(group_states)[0].shape[0]
+        if lead != num_groups:
+            raise ValueError(f"segment_fold: group_states lead axis {lead} "
+                             f"!= num_groups={num_groups}")
+        inits = jax.vmap(lambda s: make_agg(s).init(columns))(group_states)
+        nb = block_gids.shape[0]
+        if nb == 0:
+            return inits
+        if kernel_impl is not None and agg is not None \
+                and getattr(agg, "segment_kernel", None):
+            kstates = agg.segment_kernel_fold(columns, valid, block_gids,
+                                              num_groups, kernel_impl)
+            return jax.tree.map(_combine_leaf, ops, inits, kstates)
+        n2 = next(iter(columns.values())).shape[0]
+        bs = n2 // nb
 
-    def step(acc, b):
-        blk, bm = _block_at(columns, valid, b, bs)
-        return segment_block_update(make_agg, group_states, ops, blk, bm,
-                                    block_gids[b], acc), None
+        def step(acc, b):
+            blk, bm = _block_at(columns, valid, b, bs)
+            return segment_block_update(make_agg, group_states, ops, blk, bm,
+                                        block_gids[b], acc), None
 
-    acc, _ = jax.lax.scan(step, inits, jnp.arange(nb))
-    return acc
+        acc, _ = jax.lax.scan(step, inits, jnp.arange(nb))
+        return acc
 
 
 def merge_group_states(agg: Aggregate, ops, states, axes: tuple[str, ...]):
@@ -648,10 +671,11 @@ def merge_group_states(agg: Aggregate, ops, states, axes: tuple[str, ...]):
     combinators (``ops`` from :meth:`Aggregate.segment_ops`), else an
     all-gather of every segment's group-state stack folded with the
     aggregate's own generic ``merge`` (vmapped over the group axis)."""
-    if ops is not None:
-        return jax.tree.map(partial(_collective_leaf, axes=axes), ops,
-                            states)
-    return _all_gather_merge_fold(jax.vmap(agg.merge), states, axes)
+    with jax.named_scope("madjax.merge"):
+        if ops is not None:
+            return jax.tree.map(partial(_collective_leaf, axes=axes), ops,
+                                states)
+        return _all_gather_merge_fold(jax.vmap(agg.merge), states, axes)
 
 
 def _mesh_segments(mesh: Mesh, row_axes: tuple[str, ...]) -> int:
@@ -696,9 +720,9 @@ def _segment_jit(agg: Aggregate, ops, G: int, finalize: bool, schema,
     key = (id(agg), G, finalize, schema, seg_impl)
     hit = _SEGMENT_JIT_CACHE.get(key)
     if hit is not None:
-        return hit[1]
+        return hit[1], "hit"
     dummy_states = jnp.zeros((G,), jnp.int32)
-    group_final = jax.vmap(agg.final) if finalize else (lambda s: s)
+    group_final = _group_finalizer(agg, finalize)
 
     def go_segment(columns, valid, bgids):
         states = segment_fold(lambda _s: agg, dummy_states, ops,
@@ -710,7 +734,7 @@ def _segment_jit(agg: Aggregate, ops, G: int, finalize: bool, schema,
     if len(_SEGMENT_JIT_CACHE) >= _SEGMENT_JIT_MAX:
         _SEGMENT_JIT_CACHE.pop(next(iter(_SEGMENT_JIT_CACHE)))
     _SEGMENT_JIT_CACHE[key] = (agg, fn)
-    return fn
+    return fn, "miss"
 
 
 def run_grouped(agg: Aggregate, table, group_col: str | None = None,
@@ -804,7 +828,7 @@ def run_grouped(agg: Aggregate, table, group_col: str | None = None,
         method = "segment" if ops is not None else "masked"
     _record(trace_kind, engine=f"grouped-{method}", sharded=mesh is not None,
             groups=G)
-    group_final = jax.vmap(agg.final) if finalize else (lambda s: s)
+    group_final = _group_finalizer(agg, finalize)
 
     if method == "segment":
         if ops is None:
@@ -820,22 +844,21 @@ def run_grouped(agg: Aggregate, table, group_col: str | None = None,
 
         if mesh is None:
             cols_a, valid_a, bgids = view.aligned_blocks(bs, pmask)
-            seg_impl = _resolve_segment_kernel(agg, cols_a, valid_a,
-                                               bgids, G)
-            if jit:
-                schema = tuple(sorted(
-                    (k, str(v.dtype), tuple(v.shape[1:]))
-                    for k, v in data.items()))
-                return _segment_jit(agg, ops, G, finalize, schema,
-                                    seg_impl)(cols_a, valid_a, bgids)
-
-            def go_segment(columns, valid, bgids):
+            with _span("fold.dispatch", engine="grouped-segment") as sp:
+                seg_impl = _resolve_segment_kernel(agg, cols_a, valid_a,
+                                                   bgids, G)
+                if jit:
+                    schema = tuple(sorted(
+                        (k, str(v.dtype), tuple(v.shape[1:]))
+                        for k, v in data.items()))
+                    fn, sp.detail["prepared"] = _segment_jit(
+                        agg, ops, G, finalize, schema, seg_impl)
+                    return fn(cols_a, valid_a, bgids)
+                sp.detail["prepared"] = "miss"
                 states = segment_fold(lambda _s: agg, dummy_states, ops,
-                                      columns, valid, bgids, G,
+                                      cols_a, valid_a, bgids, G,
                                       agg=agg, kernel_impl=seg_impl)
                 return group_final(states)
-
-            return go_segment(cols_a, valid_a, bgids)
 
         # Sharded segment path: each segment folds its local chunk of
         # group-aligned blocks, per-group partials merge leaf-wise.
@@ -843,29 +866,33 @@ def run_grouped(agg: Aggregate, table, group_col: str | None = None,
                                                      pmask)
         # kernel resolution sees the SHARD-LOCAL shapes the kernel will
         # run on inside shard_map (sharded_blocks pads every segment to
-        # whole blocks, so the division is exact)
-        segs = _mesh_segments(mesh, row_axes)
-        _local = lambda v: jax.ShapeDtypeStruct(
-            (v.shape[0] // segs,) + v.shape[1:], v.dtype)
-        seg_impl = _resolve_segment_kernel(
-            agg, jax.tree.map(_local, dict(cols_a)), _local(valid_a),
-            _local(bgids), G)
-        in_spec = jax.tree.map(
-            lambda v: row_pspec(row_axes, v.ndim), cols_a)
+        # whole blocks, so the division is exact); the program is built
+        # anew on every call, so the prepared lookup always misses
+        with _span("fold.dispatch", engine="sharded-grouped-segment",
+                   prepared="miss"):
+            segs = _mesh_segments(mesh, row_axes)
+            _local = lambda v: jax.ShapeDtypeStruct(
+                (v.shape[0] // segs,) + v.shape[1:], v.dtype)
+            seg_impl = _resolve_segment_kernel(
+                agg, jax.tree.map(_local, dict(cols_a)), _local(valid_a),
+                _local(bgids), G)
+            in_spec = jax.tree.map(
+                lambda v: row_pspec(row_axes, v.ndim), cols_a)
 
-        def shard_segment(columns, valid, bgids):
-            states = segment_fold(lambda _s: agg, dummy_states, ops,
-                                  columns, valid, bgids, G,
-                                  agg=agg, kernel_impl=seg_impl)
-            merged = merge_group_states(agg, ops, states, row_axes)
-            return group_final(merged)
+            def shard_segment(columns, valid, bgids):
+                states = segment_fold(lambda _s: agg, dummy_states, ops,
+                                      columns, valid, bgids, G,
+                                      agg=agg, kernel_impl=seg_impl)
+                merged = merge_group_states(agg, ops, states, row_axes)
+                return group_final(merged)
 
-        mapped = jax.shard_map(
-            shard_segment, mesh=mesh,
-            in_specs=(in_spec, row_pspec(row_axes), row_pspec(row_axes)),
-            out_specs=P(), check_vma=False)
-        fn = jax.jit(mapped) if jit else mapped
-        return fn(cols_a, valid_a, bgids)
+            mapped = jax.shard_map(
+                shard_segment, mesh=mesh,
+                in_specs=(in_spec, row_pspec(row_axes),
+                          row_pspec(row_axes)),
+                out_specs=P(), check_vma=False)
+            fn = jax.jit(mapped) if jit else mapped
+            return fn(cols_a, valid_a, bgids)
 
     if method != "masked":
         raise ValueError(f"unknown method {method!r} "

@@ -69,7 +69,7 @@ from .iterative import (
 )
 from .join import Join
 from .table import Columns, GroupedView, Table
-from .trace import record as _record
+from .trace import record as _record, span as _span
 
 # ---------------------------------------------------------------------------
 # The capability matrix — which cross-cutting features each engine honors.
@@ -1041,6 +1041,11 @@ def plan(statements: Sequence[Any]) -> PhysicalPlan:
     scans, dedup sorts, select engines.  Pass order follows each pass's
     first statement; results are returned in statement order."""
     statements = list(statements)
+    with _span("plan", statements=len(statements)):
+        return _plan(statements)
+
+
+def _plan(statements: list) -> PhysicalPlan:
     groups: dict[Any, list] = {}
     order: list[Any] = []
     for i, node in enumerate(statements):

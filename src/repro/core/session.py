@@ -45,6 +45,7 @@ from .plan import (
     StreamAgg, plan,
 )
 from .table import Table
+from .trace import span
 
 _UNSET = object()
 
@@ -304,9 +305,10 @@ class Session:
             self._derived = []
             return []
         try:
-            pl = plan(self._nodes)
-            self.last_plan = pl
-            results = pl.execute()
+            with span("run", statements=len(self._nodes)):
+                pl = plan(self._nodes)
+                self.last_plan = pl
+                results = pl.execute()
             for h, post, res in zip(self._handles, self._posts, results):
                 h._value = post(res) if post is not None else res
             for h, parts, combine in self._derived:

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from .trace import span
+
 Columns = Mapping[str, jax.Array]
 
 
@@ -241,7 +243,9 @@ class Table:
         view = self.cached_group_by(key_col, num_groups)
         if view is not None:
             return view
-        view = self._group_by_uncached(key_col, num_groups)
+        with span("group_by", key_col=key_col, n_rows=self.n_rows,
+                  table=id(self)):
+            view = self._group_by_uncached(key_col, num_groups)
         self._gb_cache[(key_col, num_groups)] = (self._version, view)
         self._gb_cache[(key_col, view.num_groups)] = (self._version, view)
         return view
@@ -370,19 +374,18 @@ class Table:
         on the same key pays the sort once, whichever path asks first.
         Memoized per ``key_col`` with the same version-stamp staleness
         contract as the :meth:`group_by` memo; a miss records ONE
-        ``kind="sort"`` trace event tagged ``table=id(self)`` (the
+        ``kind="sort"`` trace span tagged ``table=id(self)`` (the
         per-table rollup in :meth:`Trace.summary` counts these), a hit
         records nothing.
         """
         hit = self._sort_cache.get(key_col)
         if hit is not None and hit[0] == self._version:
             return hit[1]
-        from .trace import record
-        record("sort", key_col=key_col, n_rows=self.n_rows,
-               table=id(self))
-        keys = self.columns[key_col]
-        perm = jnp.argsort(keys, stable=True)
-        out = (keys[perm], perm)
+        with span("sort", key_col=key_col, n_rows=self.n_rows,
+                  table=id(self)):
+            keys = self.columns[key_col]
+            perm = jnp.argsort(keys, stable=True)
+            out = (keys[perm], perm)
         self._sort_cache[key_col] = (self._version, out)
         return out
 
@@ -463,46 +466,53 @@ class GroupedView:
         them) with every row masked invalid.
         """
         bs = int(block_size)
-        counts = np.asarray(jax.device_get(self.counts))
-        starts = np.asarray(jax.device_get(self.offsets))[:-1]
-        bpg = -(-counts // bs)  # blocks per group (0 for empty groups)
-        bg_np = np.repeat(np.arange(self.num_groups), bpg).astype(np.int32)
-        ppg = bpg * bs          # padded rows per group
-        n2 = int(ppg.sum())
-        if n2 == 0:
-            # No real blocks (all groups empty / every id out of range).
-            # Still honour pad_blocks_to: emit that many sentinel blocks
-            # so sharded layouts keep their every-segment-owns-whole-
-            # blocks contract even for an empty view.  Sentinel columns
-            # are constructed, not gathered — the table may have 0 rows.
-            pad = int(pad_blocks_to) if pad_blocks_to else 0
-            cols = {
-                k: jnp.zeros((pad * bs,) + v.shape[1:], v.dtype)
-                for k, v in self.table.columns.items()
-            }
-            return (cols, jnp.zeros((pad * bs,), jnp.bool_),
-                    jnp.full((pad,), self.num_groups, jnp.int32))
-        grp = np.repeat(np.arange(self.num_groups), ppg)
-        out_start = np.concatenate([[0], np.cumsum(ppg)])[:-1]
-        local = np.arange(n2) - out_start[grp]
-        valid_np = local < counts[grp]
-        src_np = np.where(valid_np, starts[grp] + local, 0).astype(np.int32)
-        if pad_blocks_to:
-            extra = -len(bg_np) % int(pad_blocks_to)
-            if extra:
-                bg_np = np.concatenate(
-                    [bg_np,
-                     np.full(extra, self.num_groups, np.int32)])
-                src_np = np.concatenate(
-                    [src_np, np.zeros(extra * bs, np.int32)])
-                valid_np = np.concatenate(
-                    [valid_np, np.zeros(extra * bs, bool)])
-        src = jnp.asarray(src_np)
-        cols = {k: take_rows(v, src) for k, v in self.table.columns.items()}
-        valid = jnp.asarray(valid_np)
-        if base_mask is not None:
-            valid = valid & jnp.asarray(base_mask)[src]
-        return cols, valid, jnp.asarray(bg_np)
+        with span("layout.index", n_rows=self.n_rows, block_size=bs):
+            counts = np.asarray(jax.device_get(self.counts))
+            starts = np.asarray(jax.device_get(self.offsets))[:-1]
+            bpg = -(-counts // bs)  # blocks per group (0 for empty groups)
+            bg_np = np.repeat(np.arange(self.num_groups),
+                              bpg).astype(np.int32)
+            ppg = bpg * bs          # padded rows per group
+            n2 = int(ppg.sum())
+            if n2 == 0:
+                # No real blocks (all groups empty / every id out of
+                # range).  Still honour pad_blocks_to: emit that many
+                # sentinel blocks so sharded layouts keep their
+                # every-segment-owns-whole-blocks contract even for an
+                # empty view.  Sentinel columns are constructed, not
+                # gathered — the table may have 0 rows.
+                pad = int(pad_blocks_to) if pad_blocks_to else 0
+                cols = {
+                    k: jnp.zeros((pad * bs,) + v.shape[1:], v.dtype)
+                    for k, v in self.table.columns.items()
+                }
+                return (cols, jnp.zeros((pad * bs,), jnp.bool_),
+                        jnp.full((pad,), self.num_groups, jnp.int32))
+            grp = np.repeat(np.arange(self.num_groups), ppg)
+            out_start = np.concatenate([[0], np.cumsum(ppg)])[:-1]
+            local = np.arange(n2) - out_start[grp]
+            valid_np = local < counts[grp]
+            src_np = np.where(valid_np, starts[grp] + local,
+                              0).astype(np.int32)
+            if pad_blocks_to:
+                extra = -len(bg_np) % int(pad_blocks_to)
+                if extra:
+                    bg_np = np.concatenate(
+                        [bg_np,
+                         np.full(extra, self.num_groups, np.int32)])
+                    src_np = np.concatenate(
+                        [src_np, np.zeros(extra * bs, np.int32)])
+                    valid_np = np.concatenate(
+                        [valid_np, np.zeros(extra * bs, bool)])
+            src = jnp.asarray(src_np)
+            valid = jnp.asarray(valid_np)
+            if base_mask is not None:
+                valid = valid & jnp.asarray(base_mask)[src]
+            bgids = jnp.asarray(bg_np)
+        with span("layout.gather", columns=len(self.table.columns)):
+            cols = {k: take_rows(v, src)
+                    for k, v in self.table.columns.items()}
+        return cols, valid, bgids
 
     def sharded_blocks(self, mesh: Mesh, row_axes=("data",),
                        block_size: int = 4096,

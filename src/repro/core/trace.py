@@ -51,24 +51,55 @@ because it reads MORE THAN ONE table (a join): the cache keys on a
 single table's version, so caching a join result could serve stale
 state after only the dimension mutated; the loud event makes the
 refusal observable (see :func:`repro.core.plan.semantic_fingerprint`).
+
+**Spans.**  :func:`span` is :func:`record` with an extent: a context
+manager that records one event when it opens and stamps it with
+``t0_ns`` / ``t1_ns`` (``time.perf_counter_ns()``) and ``parent``, the
+index in :attr:`Trace.events` of the span that encloses it on the same
+thread (``None`` at the top; the server runs statements on its drain
+thread, so each thread keeps its own stack).  Point events from
+:func:`record` leave all three ``None``.  The grouped statement path
+opens ``run`` (:meth:`Session.run`) ⊃ ``plan`` (:func:`plan`),
+``sort`` and ``group_by`` (memo misses of :meth:`Table.sort_permutation`
+and :meth:`Table.group_by`), ``layout.index`` and ``layout.gather``
+(:meth:`GroupedView.aligned_blocks`: the host's index build and uploads,
+then the gather dispatches) and ``fold.dispatch`` (kernel resolution,
+prepared-program lookup and the call; ``detail["prepared"]`` is
+``"hit"`` or ``"miss"``).  :meth:`Trace.spans` lists them and
+:meth:`Trace.summary` sums their seconds per kind under ``"span_s"``.
+Every span also opens ``jax.profiler.TraceAnnotation("madjax.<kind>")``,
+so a profiler trace shows it on the host's timeline beside the device's
+programs; inside the programs, ``jax.named_scope`` names ``madjax.fold``,
+``madjax.finalize`` and ``madjax.merge`` do that job.  Spans are always
+on: with no active trace and no profiler a span costs the annotation's
+check and nothing else.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import threading
+import time
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
 class Event:
-    kind: str               # "scan" | "sort" | "fit" | "delta" | "kernel"
+    kind: str               # points: "scan" | "fit" | "delta" | "kernel"
     #                       | "admission" | "cache_hit" | "join"
-    #                       | "cache_reject"
+    #                       | "cache_reject"; spans: "run" | "plan"
+    #                       | "sort" | "group_by" | "layout.index"
+    #                       | "layout.gather" | "fold.dispatch"
     engine: str | None      # "local" / "sharded" / "grouped-segment" / ...;
     # for kind="kernel" this is the RESOLVED implementation ("ref" /
     # "pallas"), with detail carrying the kernel name and requested impl
     detail: dict[str, Any]
+    t0_ns: int | None = None    # span opened (perf_counter_ns); None: point
+    t1_ns: int | None = None    # span closed; None while open
+    parent: int | None = None   # index of the enclosing span's event
 
 
 class Trace:
@@ -136,8 +167,16 @@ class Trace:
         hit path."""
         return self._kind("cache_hit")
 
+    def spans(self, kind: str | None = None) -> list[Event]:
+        """Span events (those with a ``t0_ns``), of one ``kind`` or all,
+        in the order they opened."""
+        return [e for e in self.events if e.t0_ns is not None
+                and (kind is None or e.kind == kind)]
+
     def summary(self) -> dict:
-        """Counts per event kind, plus the admission windows' aggregate
+        """Counts per event kind, seconds per span kind under
+        ``"span_s"`` (closed spans; a nested span counts in its own kind
+        and inside its parent's), plus the admission windows' aggregate
         sharing tallies (``scans_saved`` / ``deduped`` summed across
         windows) — what benches and serving logs print.  When admission
         events are present, ``out["by_table"]`` breaks the serving
@@ -146,8 +185,14 @@ class Trace:
         scans saved, dedups and cache hits — the cross-table rollup for
         per-table admission windows."""
         out: dict[str, Any] = {}
+        span_s: dict[str, float] = {}
         for e in self.events:
             out[e.kind] = out.get(e.kind, 0) + 1
+            if e.t0_ns is not None and e.t1_ns is not None:
+                span_s[e.kind] = (span_s.get(e.kind, 0.0)
+                                  + (e.t1_ns - e.t0_ns) / 1e9)
+        if span_s:
+            out["span_s"] = span_s
         sorts = self._kind("sort")
         if sorts:
             # per-table sort rollup: sort dedup across a star schema
@@ -179,12 +224,72 @@ class Trace:
 
 
 _ACTIVE: list[Trace] = []
+# guards _ACTIVE, and an event's append with the index it gets
+_LOCK = threading.Lock()
+_THREAD = threading.local()    # .stack: {trace: event index} per open span
 
 
 def record(kind: str, engine: str | None = None, **detail: Any) -> None:
     """Record one event on every active trace (no-op when none are)."""
-    for t in _ACTIVE:
-        t.events.append(Event(kind, engine, detail))
+    if not _ACTIVE:
+        return
+    with _LOCK:
+        for t in _ACTIVE:
+            t.events.append(Event(kind, engine, detail))
+
+
+class span:
+    """Record one timed event of ``kind`` on every active trace for the
+    extent of a ``with`` block, and open
+    ``jax.profiler.TraceAnnotation("madjax.<kind>")`` for the same
+    extent::
+
+        with span("layout.index", rows=n) as sp:
+            ...
+            sp.detail["blocks"] = nb     # shared by every trace's event
+
+    The event's ``parent`` is the enclosing span's index in the same
+    trace, from a per-thread stack.  Host-side only: a span opened
+    inside a traced (jitted, vmapped, shard-mapped) function would time
+    the tracing, not the work; inside a program use ``jax.named_scope``.
+    """
+
+    __slots__ = ("kind", "engine", "detail", "_note", "_opened")
+
+    def __init__(self, kind: str, engine: str | None = None,
+                 **detail: Any):
+        self.kind = kind
+        self.engine = engine
+        self.detail = detail
+        self._opened: list[Event] | None = None
+
+    def __enter__(self) -> "span":
+        self._note = TraceAnnotation(f"madjax.{self.kind}")
+        self._note.__enter__()
+        if not _ACTIVE:
+            return self
+        stack = _THREAD.__dict__.setdefault("stack", [])
+        parents = stack[-1] if stack else {}
+        opened, at = [], {}
+        t0 = time.perf_counter_ns()
+        with _LOCK:
+            for t in _ACTIVE:
+                ev = Event(self.kind, self.engine, self.detail, t0, None,
+                           parents.get(t))
+                t.events.append(ev)
+                opened.append(ev)
+                at[t] = len(t.events) - 1
+        stack.append(at)
+        self._opened = opened
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._opened is not None:
+            t1 = time.perf_counter_ns()
+            _THREAD.stack.pop()
+            for ev in self._opened:
+                ev.t1_ns = t1
+        self._note.__exit__(*exc)
 
 
 @contextlib.contextmanager
@@ -198,8 +303,10 @@ def trace_execution() -> Iterator[Trace]:
     Nestable; every active trace sees every event.
     """
     t = Trace()
-    _ACTIVE.append(t)
+    with _LOCK:
+        _ACTIVE.append(t)
     try:
         yield t
     finally:
-        _ACTIVE.remove(t)
+        with _LOCK:
+            _ACTIVE.remove(t)

@@ -114,6 +114,9 @@ def serve_analytics(*, rows: int = 100_000, dims: int = 8,
               f"deduped={summ.get('deduped', 0)} "
               f"scans_saved={summ.get('scans_saved', 0)} | "
               f"{stmts / dt:.0f} stmts/s")
+        if "span_s" in summ:
+            print(f"round {rnd}: host s by span " + " ".join(
+                f"{k}={v:.4f}" for k, v in sorted(summ["span_s"].items())))
     stats = dict(server.stats)
     server.close()
     print(f"lifetime: {stats}")
