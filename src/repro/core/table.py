@@ -52,6 +52,78 @@ def take_rows(v: jax.Array, idx: jax.Array) -> jax.Array:
     return out.T.reshape(idx.shape + v.shape[1:])
 
 
+@partial(jax.jit, static_argnums=4)
+def take_rows_blocked(columns: dict, mask: jax.Array | None,
+                      block_src: jax.Array, block_rows: jax.Array,
+                      block_size: int) -> tuple[dict, jax.Array]:
+    """Copy row windows into blocks: output block ``b`` holds rows
+    ``block_src[b] : block_src[b] + block_rows[b]`` of every column, then
+    zeros up to ``block_size`` rows.
+
+    Returns the blocked columns and their validity (the first
+    ``block_rows[b]`` rows of each block, ANDed with ``mask`` copied by
+    the same windows when given).  A loop over blocks slices all columns
+    of one block at a time, each ``(n, k)`` column from its ``(k, n)``
+    transpose: that keeps the TPU's rows-on-lanes layout as it is (see
+    :func:`take_rows`), where slicing ``(n, k)`` itself, or a ``vmap`` of
+    window slices, asks for a relayouted copy of the table.
+
+    Real blocks (``block_rows > 0``) come first and sentinels, which
+    stay zero, after them; the real blocks' ``block_src`` must rise with
+    ``b``, as a group-aligned layout's do.  The windows that would run
+    past the table's end (``dynamic_slice`` clamps their start) then form
+    a suffix of the real blocks, and a second loop reads them from the
+    clamped window and shifts them back.
+    """
+    bs = block_size
+    # each column as (features, rows), the rows-on-lanes layout as it is;
+    # a one-dimensional column as it is, which (1, rows) would relayout
+    flat = {k: v if v.ndim == 1 else v.reshape(v.shape[0], -1).T
+            for k, v in columns.items()}
+    n = next(iter(columns.values())).shape[0]
+    wsz = min(bs, n)
+    real = block_rows > 0
+    n_real = jnp.sum(real, dtype=jnp.int32)
+
+    def copy_blocks(shifted):
+        def window(v, start, cs):
+            w = jax.lax.dynamic_slice_in_dim(v, cs, wsz, axis=-1)
+            if shifted:
+                w = jnp.concatenate(
+                    [w, jnp.zeros(w.shape[:-1] + (bs,), w.dtype)], axis=-1)
+                w = jax.lax.dynamic_slice_in_dim(w, start - cs, bs, axis=-1)
+            return w
+
+        def copy(b, carry):
+            outs, valid = dict(carry[0]), carry[1]
+            start, rows = block_src[b], block_rows[b]
+            cs = jnp.clip(start, 0, n - wsz)
+            keep = jax.lax.iota(jnp.int32, bs) < rows
+            for k, v in flat.items():
+                w = window(v, start, cs)
+                outs[k] = jax.lax.dynamic_update_slice_in_dim(
+                    outs[k], jnp.where(keep, w, jnp.zeros_like(w)), b * bs,
+                    axis=-1)
+            if mask is not None:
+                keep = keep & window(mask, start, cs)
+            return outs, jax.lax.dynamic_update_slice(valid, keep, (b * bs,))
+        return copy
+
+    nb = block_src.shape[0]
+    carry = ({k: jnp.zeros(v.shape[:-1] + (nb * bs,), v.dtype)
+              for k, v in flat.items()},
+             jnp.zeros((nb * bs,), bool))
+    n_fit = jnp.int32(0)
+    if wsz == bs:
+        # windows that fit: every real block but those that start within
+        # bs rows of the end
+        n_fit = jnp.sum(real & (block_src <= n - bs), dtype=jnp.int32)
+        carry = jax.lax.fori_loop(0, n_fit, copy_blocks(False), carry)
+    outs, valid = jax.lax.fori_loop(n_fit, n_real, copy_blocks(True), carry)
+    return {k: o.T.reshape((nb * bs,) + columns[k].shape[1:])
+            for k, o in outs.items()}, valid
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Table:
@@ -453,6 +525,10 @@ class GroupedView:
         Returns ``(columns, valid, block_gids)``: columns with leading axis
         ``n_blocks * block_size``, a validity mask over real (and
         base-mask-passing) rows, and the single group id of each block.
+        Padding rows are zeros.  Every block is one window of the
+        partitioned copy, so the host builds O(blocks) offsets, not an
+        O(rows) index, and the device copies windows
+        (:func:`take_rows_blocked`).
         Empty groups get no blocks; out-of-range ids fall outside every
         segment and are dropped.  ``base_mask`` must already be in
         partitioned order (see :meth:`permute`).  Padding overhead is
@@ -472,9 +548,8 @@ class GroupedView:
             bpg = -(-counts // bs)  # blocks per group (0 for empty groups)
             bg_np = np.repeat(np.arange(self.num_groups),
                               bpg).astype(np.int32)
-            ppg = bpg * bs          # padded rows per group
-            n2 = int(ppg.sum())
-            if n2 == 0:
+            n_real = len(bg_np)
+            if n_real == 0:
                 # No real blocks (all groups empty / every id out of
                 # range).  Still honour pad_blocks_to: emit that many
                 # sentinel blocks so sharded layouts keep their
@@ -488,30 +563,26 @@ class GroupedView:
                 }
                 return (cols, jnp.zeros((pad * bs,), jnp.bool_),
                         jnp.full((pad,), self.num_groups, jnp.int32))
-            grp = np.repeat(np.arange(self.num_groups), ppg)
-            out_start = np.concatenate([[0], np.cumsum(ppg)])[:-1]
-            local = np.arange(n2) - out_start[grp]
-            valid_np = local < counts[grp]
-            src_np = np.where(valid_np, starts[grp] + local,
-                              0).astype(np.int32)
-            if pad_blocks_to:
-                extra = -len(bg_np) % int(pad_blocks_to)
-                if extra:
-                    bg_np = np.concatenate(
-                        [bg_np,
-                         np.full(extra, self.num_groups, np.int32)])
-                    src_np = np.concatenate(
-                        [src_np, np.zeros(extra * bs, np.int32)])
-                    valid_np = np.concatenate(
-                        [valid_np, np.zeros(extra * bs, bool)])
-            src = jnp.asarray(src_np)
-            valid = jnp.asarray(valid_np)
-            if base_mask is not None:
-                valid = valid & jnp.asarray(base_mask)[src]
+            # block j of group g starts at source row starts[g] + j*bs
+            # and holds min(bs, counts[g] - j*bs) valid rows
+            j = np.arange(n_real) - np.repeat(np.cumsum(bpg) - bpg, bpg)
+            src_np = np.repeat(starts, bpg) + j * bs
+            rows_np = np.minimum(bs, np.repeat(counts, bpg) - j * bs)
+            extra = -n_real % int(pad_blocks_to) if pad_blocks_to else 0
+            if extra:
+                bg_np = np.concatenate(
+                    [bg_np, np.full(extra, self.num_groups, np.int32)])
+                src_np = np.concatenate([src_np, np.zeros(extra, np.int64)])
+                rows_np = np.concatenate([rows_np,
+                                          np.zeros(extra, np.int64)])
+            block_src = jnp.asarray(src_np.astype(np.int32))
+            block_rows = jnp.asarray(rows_np.astype(np.int32))
             bgids = jnp.asarray(bg_np)
-        with span("layout.gather", columns=len(self.table.columns)):
-            cols = {k: take_rows(v, src)
-                    for k, v in self.table.columns.items()}
+        with span("layout.gather", columns=len(self.table.columns),
+                  blocks=n_real, row_gathered=0):
+            cols, valid = take_rows_blocked(
+                dict(self.table.columns), base_mask, block_src, block_rows,
+                bs)
         return cols, valid, bgids
 
     def sharded_blocks(self, mesh: Mesh, row_axes=("data",),

@@ -62,8 +62,10 @@ thread, so each thread keeps its own stack).  Point events from
 opens ``run`` (:meth:`Session.run`) ⊃ ``plan`` (:func:`plan`),
 ``sort`` and ``group_by`` (memo misses of :meth:`Table.sort_permutation`
 and :meth:`Table.group_by`), ``layout.index`` and ``layout.gather``
-(:meth:`GroupedView.aligned_blocks`: the host's index build and uploads,
-then the gather dispatches) and ``fold.dispatch`` (kernel resolution,
+(:meth:`GroupedView.aligned_blocks`: the host's block index and its
+upload, then the window copy's dispatch, with ``detail["blocks"]`` the
+window-copied blocks and ``detail["row_gathered"]`` the rows that took
+an element gather instead, none today) and ``fold.dispatch`` (kernel resolution,
 prepared-program lookup and the call; ``detail["prepared"]`` is
 ``"hit"`` or ``"miss"``).  :meth:`Trace.spans` lists them and
 :meth:`Trace.summary` sums their seconds per kind under ``"span_s"``.
