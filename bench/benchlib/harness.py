@@ -8,6 +8,10 @@ JAX's persistent cache keeps every program, so what the program traces
 again per statement is loaded, and counted in ``host.compile_s.batch``);
 ``memory_peak_bytes`` is read before the reference runs; the reference
 runs after the program's state is freed.
+
+A cell on more than one chip runs as that many segments: its table is
+made row-sharded over the first ``chips`` devices, each chip drawing its
+own rows, and the reference reads it shard by shard.
 """
 
 from __future__ import annotations
@@ -23,6 +27,7 @@ from pathlib import Path
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from . import data, devtrace, spec, statements, traffic as traffic_mod
 
@@ -98,11 +103,42 @@ def device_info() -> dict:
             "device_kind": d.device_kind, "count": len(jax.devices())}
 
 
-def memory_peak_bytes() -> int | None:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in jax.devices()]
-    peaks = [p for p in peaks if p is not None]
-    return max(peaks) if peaks else None
+def memory_peaks() -> list:
+    """Each device's peak bytes in use, ``None`` where it reports none."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.devices()]
+
+
+def cell_mesh(cell: spec.Cell, rows: int | None = None) -> Mesh | None:
+    """``None`` for a one-chip cell; else a mesh of the first
+    ``cell.chips`` devices on the program's row axis ``"data"``, one
+    segment per chip.  Raises where the rows do not divide over them."""
+    if cell.chips == 1:
+        return None
+    n = int(rows or cell.config["rows"])
+    if n % cell.chips:
+        raise ValueError(f"cell {cell.name}: {n} rows do not divide over "
+                         f"its {cell.chips} chips")
+    devices = jax.devices()[:cell.chips]
+    if len(devices) < cell.chips:
+        raise ValueError(f"cell {cell.name} needs {cell.chips} devices; "
+                         f"JAX sees {len(devices)}")
+    return Mesh(np.asarray(devices), ("data",))
+
+
+def make_table(cell: spec.Cell, seed: int, mesh: Mesh | None,
+               rows: int | None = None):
+    """``(columns, table)``: the cell's columns made from ``seed``, and the
+    table the traffic reads, over ``mesh``'s chips row by row (Greenplum's
+    DISTRIBUTED BY over the cell's segments) where there is one."""
+    from repro.core import Table
+    cfg = cell.config
+    cols = data.make_columns(cfg, seed, rows, mesh)
+    jax.block_until_ready(cols)
+    named = {k: cols[k] for k in cfg["columns"]}
+    if mesh is None:
+        return cols, Table.from_columns(named)
+    return cols, Table(named, mesh, ("data",))
 
 
 def _closed_loop(catalog, table, order, seconds: float, session_cls
@@ -149,15 +185,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     cuts the table (tests on the CPU); ``t_origin`` is the
     ``time.perf_counter()`` reading of the process's start;
     ``records_out`` receives the window's statement records."""
-    from repro.core import Session, Table, trace_execution
+    from repro.core import Session, trace_execution
 
     cfg, mix = cell.config, cell.traffic
+    mesh = cell_mesh(cell, rows)
     monitor = Monitor()
 
     # -- set-up: the table, then one statement of each kind in the mix
-    cols = data.make_columns(cfg, seed, rows)
-    jax.block_until_ready(cols)
-    table = Table.from_columns({k: cols[k] for k in cfg["columns"]})
+    cols, table = make_table(cell, seed, mesh, rows)
     catalog = [statements.make(p, cfg) for p in mix["statements"]]
     order = traffic_mod.statement_order(mix, MAX_STATEMENTS, seed)
     warm = _closed_loop(catalog, table, sorted(set(order.tolist())),
@@ -189,13 +224,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     t_end = max((r.t1 for r in records if r.t1 is not None),
                 default=t_start)
     window_s = t_end - t_start
-    peak = memory_peak_bytes()
+    peaks = memory_peaks()
+    peak = max((p for p in peaks if p is not None), default=None)
     kernels = sorted({(e.detail.get("name"), e.engine)
                       for e in ptrace.events if e.kind == "kernel"})
     lat_ms = [1e3 * (r.t1 - r.t0) for r in done]
     _log(f"window: {len(records)} statements in {window_s} s; latency ms "
          f"{_quantiles(lat_ms)}; compile_s in window {compile_s}")
-    _log(f"peak_bytes_in_use={peak}; kernels resolved: {kernels or 'none'}")
+    _log(f"peak_bytes_in_use={peak} (per device: {peaks}); "
+         f"kernels resolved: {kernels or 'none'}")
     for r in records:
         if r.error:
             _log(f"statement {r.index} ({r.kind}) failed: {r.error}")

@@ -24,8 +24,20 @@ def _rows_at(v, start, size):
 
 
 def host_chunks(cols: dict, names, chunk: int = CHUNK):
-    """``{name: numpy rows}`` for consecutive row chunks of ``cols``.
-    One compiled slice per column shape: the start is an argument."""
+    """``{name: numpy rows}`` for consecutive row chunks of ``cols``, read
+    shard by shard in row order, each chunk sliced on the device that
+    holds it, so that no program sees more than one shard of a column
+    sharded by rows (a column on one device is its one shard)."""
+    by_start = {k: {(s.index[0].start or 0): s.data
+                    for s in cols[k].addressable_shards} for k in names}
+    for start in sorted(by_start[names[0]]):
+        yield from _chunks({k: by_start[k][start] for k in names}, chunk)
+
+
+def _chunks(cols: dict, chunk: int):
+    """:func:`host_chunks` of columns on one device.  One compiled slice
+    per column shape: the start is an argument."""
+    names = list(cols)
     n = cols[names[0]].shape[0]
     size = min(chunk, n)
     for a in range(0, n, size):

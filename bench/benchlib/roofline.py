@@ -1,11 +1,14 @@
-"""Chip peaks, and the work a statement needs by its shapes.
+"""Chip peaks, and the least time a statement needs by its shapes.
 
-The operations and bytes are those of the statement's mathematics, not of
-any implementation: a later change that computes the same answer another
-way is measured against the same least time.
+The operations and bytes, from each kind's file (``bench/kinds/``), are
+those of the statement's mathematics, not of any implementation: a later
+change that computes the same answer another way is measured against the
+same least time.
 """
 
 from __future__ import annotations
+
+from . import spec
 
 # Published peaks of one chip, keyed by ``jax.Device.device_kind``.
 # Source: Google Cloud documentation, "TPU v5e" (system architecture):
@@ -26,27 +29,13 @@ def peaks(device_kind: str) -> dict:
                        f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
-def linregr_flops(rows: int, k: int) -> float:
-    """X^T X and X^T y over ``rows`` rows of ``k`` variables."""
-    return 2.0 * rows * k * k + 2.0 * rows * k
-
-
-def linregr_bytes(rows: int, k: int, itemsize: int = 4) -> float:
-    """x and y read once."""
-    return float(rows) * (k + 1) * itemsize
-
-
-WORK = {
-    "linregr": (linregr_flops, linregr_bytes),
-    "grouped_linregr": (linregr_flops, linregr_bytes),
-}
-
-
-def least_seconds(kind: str, rows: int, k: int, device_kind: str) -> float:
-    """The least time the chip could fold one statement of ``kind``:
-    the larger of its operations over peak FLOP/s and its bytes over
-    peak HBM bandwidth."""
-    flops, nbytes = WORK[kind]
+def least_seconds(kind: str, rows: int, k: int, device_kind: str,
+                  chips: int = 1) -> float:
+    """The least time ``chips`` chips could fold one statement of ``kind``
+    (its file ``bench/kinds/<kind>.py`` gives the work): the larger of its
+    operations over their peak FLOP/s and its bytes over their peak HBM
+    bandwidth."""
+    work = spec.kind(kind)
     p = peaks(device_kind)
-    return max(flops(rows, k) / p["flops_per_s"],
-               nbytes(rows, k) / p["hbm_bytes_per_s"])
+    return max(work.flops(rows, k) / (chips * p["flops_per_s"]),
+               work.bytes(rows, k) / (chips * p["hbm_bytes_per_s"]))

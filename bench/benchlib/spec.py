@@ -7,13 +7,15 @@ or one per-layer metric is a file of its own, named after it:
     bench/traffic/<traffic>.json     the statement mix and how it arrives
     bench/workloads/<cell>.json      the cell's limits for ``correct``
     bench/metrics/<metric>.py        ``read(ctx)``: one per-layer metric
+    bench/kinds/<kind>.py            one statement kind a mix can name
 
-so a later change adds a cell, a mix or a metric by adding files.
+so a later change adds a cell, a mix, a metric or a kind by adding files.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -73,13 +75,24 @@ def cell_from_files(entry: dict, end_to_end: list, per_layer: list,
         per_layer=[m for m in per_layer if _reports(m, name)])
 
 
-def metric_reader(name: str, bench: Path = BENCH
-                  ) -> Callable[[Any], float | None]:
-    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = bench / "metrics" / f"{name}.py"
-    mod_name = "bench_metric_" + "".join(
-        c if c.isalnum() else "_" for c in name)
+def _load(path: Path, prefix: str):
+    mod_name = prefix + "".join(c if c.isalnum() else "_"
+                                for c in path.stem)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench: Path = BENCH
+                  ) -> Callable[[Any], float | None]:
+    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
+    return _load(bench / "metrics" / f"{name}.py", "bench_metric_").read
+
+
+@functools.cache
+def kind(name: str, bench: Path = BENCH):
+    """The module ``bench/kinds/<name>.py``: ``Statement(params, cfg)``,
+    ``flops(rows, k)`` and ``bytes(rows, k)`` (see
+    :mod:`benchlib.statements`), loaded once per process."""
+    return _load(bench / "kinds" / f"{name}.py", "bench_kind_")

@@ -1,59 +1,24 @@
-"""Statement kinds a traffic mix can name, issued as an analyst issues them.
+"""Statement kinds a traffic mix can name, each a file ``kinds/<kind>.py``.
 
-Each kind issues one statement through the ``Session`` sugar of the system
-under test and turns its answer into numpy.  Its reference is a fold over
-host chunks of the benchmark's own columns (``ref_state`` per chunk,
-``ref_merge`` across chunks, ``ref_final``).  ``gaps`` says how far an
-answer lies from the reference, per number compared.
+A kind's file holds ``Statement(params, cfg)``: ``issue`` sends one
+statement through the ``Session`` sugar of the system under test and
+``answer`` turns its answer into numpy; the reference is a fold over host
+chunks of the benchmark's own columns (``ref_state`` per chunk,
+``ref_merge`` across chunks, ``ref_final``), and ``gaps`` says how far an
+answer lies from it, per number compared.  Beside the class,
+``flops(rows, k)`` and ``bytes(rows, k)`` give the statement's work by its
+shapes (:mod:`benchlib.roofline`).  A new kind is a new file.
 """
 
 from __future__ import annotations
 
-import numpy as np
-
-from . import reference as ref_mod
-
-
-class GroupedLinregr:
-    """``SELECT g, (linregr(y, x)).* FROM t GROUP BY g``."""
-
-    kind = "grouped_linregr"
-
-    def __init__(self, params: dict, cfg: dict):
-        self.group_col = params.get("group_col", "g")
-        self.groups = int(cfg["groups"])
-        self.k = int(cfg["k"])
-        self.columns = ["x", "y", self.group_col]
-
-    def issue(self, session, table):
-        from repro.methods.linregr import LinregrAggregate
-        return session.grouped_scan(LinregrAggregate(), table,
-                                    self.group_col, self.groups,
-                                    columns={"x": "x", "y": "y"})
-
-    @staticmethod
-    def answer(res) -> dict:
-        return {"coef": np.asarray(res.coef), "r2": np.asarray(res.r2),
-                "std_err": np.asarray(res.std_err),
-                "n": np.asarray(res.num_rows)}
-
-    def ref_state(self, chunk: dict) -> dict:
-        return ref_mod.ols_chunk(chunk, self.k, self.groups, self.group_col)
-
-    ref_merge = staticmethod(ref_mod.add)
-    ref_final = staticmethod(ref_mod.ols)
-    gaps = staticmethod(ref_mod.ols_gaps)
-
-    def reference(self, cols: dict) -> dict:
-        """The reference answer for the whole of ``cols``."""
-        return fold_reference([self], cols)[0]
-
-
-KINDS = {"grouped_linregr": GroupedLinregr}
+from . import reference as ref_mod, spec
 
 
 def make(params: dict, cfg: dict):
-    return KINDS[params["kind"]](params, cfg)
+    """The statement ``params`` describes, of the kind ``params["kind"]``,
+    over the table of ``cfg``."""
+    return spec.kind(params["kind"]).Statement(params, cfg)
 
 
 def fold_reference(entries, cols: dict) -> list:

@@ -1,9 +1,11 @@
 """Device time of the grouped layout per completed statement (ms).
 
-The grouped layout's programs are the partitioning sort and the
-column-at-a-time row gathers (``jit_take_rows``) that build the partitioned
-and the group-aligned copies of the table.  Nothing to read where none ran
-in the window."""
+The grouped layout's programs are the partitioning sort (``jit_argsort``,
+``jit_sort``), the gather that builds the partitioned copy
+(``jit_take_rows``) and the block-window copy that builds the
+group-aligned one (``jit_take_rows_blocked``), all matched by prefix; in
+the window the sort and the partitioned copy are memoized, so the window
+copy is what it reads.  Nothing to read where none ran in the window."""
 
 from benchlib import devtrace
 

@@ -4,10 +4,12 @@ A whole run of each cell of ``BENCHMARK.json`` is driven on the CPU at a
 small size, past the harness's look for a chip, with the timed path sound
 and then broken underneath in each way the cell can break: a fold step
 that returns its state unchanged, half of each batch of rows left out,
-and an answer altered where it is produced.  (No cell spans chips, so
-there is no exchange between chips to leave out.)  The control -- the
-program with its linregr matmuls one precision step down, at HIGH -- must
-come out not correct through the same comparison.
+and an answer altered where it is produced.  (No cell of
+``BENCHMARK.json`` spans chips yet: the exchange between chips left out
+is driven on a four-chip cell made of files alone, in
+``test_bench_chips.py``.)  The control -- the program with its linregr
+matmuls one precision step down, at HIGH -- must come out not correct
+through the same comparison.
 """
 
 from __future__ import annotations

@@ -43,7 +43,6 @@ def test_mix_counts_follow_weights_and_popularity():
 @pytest.mark.parametrize("cell", [w["name"] for w in
                                   spec.load_benchmark()["workloads"]])
 def test_every_cells_mix_names_known_statement_kinds(cell):
-    from benchlib import statements
     mix = spec.load_cell(cell).traffic
     assert mix["statements"]
-    assert all(p["kind"] in statements.KINDS for p in mix["statements"])
+    assert all(spec.kind(p["kind"]).Statement for p in mix["statements"])
